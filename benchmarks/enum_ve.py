@@ -40,9 +40,6 @@ import sys
 import time
 from pathlib import Path
 
-import jax
-import jax.numpy as jnp
-
 REPO = Path(__file__).resolve().parent.parent
 
 
@@ -52,6 +49,8 @@ REPO = Path(__file__).resolve().parent.parent
 
 
 def chain_inputs(T: int, K: int, seed: int = 0):
+    import jax
+
     key = jax.random.PRNGKey(seed)
     trans = jax.random.normal(key, (T, K, K))
     obs = jax.random.normal(jax.random.fold_in(key, 1), (T, K))
@@ -75,6 +74,9 @@ def build_chain_factors(T: int, K: int, trans, obs, prior):
 
 
 def time_contract(T: int, K: int, dispatch: str, reps: int = 10):
+    import jax
+    import jax.numpy as jnp
+
     from repro.infer.traceenum_elbo import contract_log_factors
 
     trans, obs, prior = chain_inputs(T, K)
@@ -103,20 +105,31 @@ def time_contract(T: int, K: int, dispatch: str, reps: int = 10):
     }
 
 
-def time_contract_budgeted(T: int, K: int, dispatch: str, budget_s: float):
-    """Run `time_contract` in a subprocess with a wall-clock budget: the
-    pairwise path at T=512 spends its time inside XLA compilation, which
-    cannot be interrupted cooperatively."""
-    cmd = [sys.executable, str(Path(__file__).resolve()), "--worker", str(T), str(K), dispatch]
+def _worker(args, timeout=None):
+    """Run this script's worker entry in a child process and return its last
+    stdout line as JSON (earlier lines are progress, echoed). The parent never
+    imports JAX: a device belongs to one process, so every measurement runs in
+    a child that starts after the previous one has exited."""
+    cmd = [sys.executable, str(Path(__file__).resolve())] + args
     env = dict(os.environ)
     env["PYTHONPATH"] = f"{REPO / 'src'}{os.pathsep}{env.get('PYTHONPATH', '')}"
     # inherit the parent's platform untouched: both sides of the winner
     # comparison must run on the same device (ci.sh exports JAX_PLATFORMS=cpu)
+    out = subprocess.run(
+        cmd, capture_output=True, text=True, timeout=timeout, check=True, env=env
+    )
+    lines = out.stdout.strip().splitlines()
+    for line in lines[:-1]:
+        print(line)
+    return json.loads(lines[-1])
+
+
+def time_contract_budgeted(T: int, K: int, dispatch: str, budget_s: float):
+    """Run `time_contract` in a subprocess with a wall-clock budget: the
+    pairwise path at T=512 spends its time inside XLA compilation, which
+    cannot be interrupted cooperatively."""
     try:
-        out = subprocess.run(
-            cmd, capture_output=True, text=True, timeout=budget_s, check=True, env=env
-        )
-        return json.loads(out.stdout.strip().splitlines()[-1])
+        return _worker(["--worker", str(T), str(K), dispatch], timeout=budget_s)
     except subprocess.TimeoutExpired:
         return {"T": T, "K": K, "dispatch": dispatch, "timed_out": True, "budget_s": budget_s}
     except subprocess.CalledProcessError as e:
@@ -129,6 +142,9 @@ def time_contract_budgeted(T: int, K: int, dispatch: str, budget_s: float):
 
 
 def model_stage(hmm_T: int, hmm_K: int, gmm_N: int, steps: int, log=print):
+    import jax
+    import jax.numpy as jnp
+
     from repro import distributions as dist
     from repro import optim
     from repro.core import handlers
@@ -224,44 +240,34 @@ def model_stage(hmm_T: int, hmm_K: int, gmm_N: int, steps: int, log=print):
 # ---------------------------------------------------------------------------
 
 
-def main(argv=None):
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--smoke", action="store_true", help="CI-sized run")
-    ap.add_argument("--json", default=str(REPO / "BENCH_enum.json"), help="output path")
-    ap.add_argument("--budget", type=float, default=None,
-                    help="wall-clock budget (s) for the pairwise T=512 attempt")
-    ap.add_argument("--worker", nargs=3, metavar=("T", "K", "DISPATCH"),
-                    help=argparse.SUPPRESS)  # internal: budgeted subprocess entry
-    args = ap.parse_args(argv)
+BIG_T, BIG_K = 512, 32
 
-    if args.worker:
-        T, K, dispatch = int(args.worker[0]), int(args.worker[1]), args.worker[2]
-        print(json.dumps(time_contract(T, K, dispatch, reps=5)))
-        return 0
+
+def planned_stage(smoke: bool) -> dict:
+    """Everything but the budgeted pairwise T=512 attempt, in one worker:
+    matched-T parity, the planned T=512 chain, the plan cache, the models."""
+    import jax
 
     from repro.infer import clear_plan_cache, plan_cache_stats
     from repro.launch.compile_cache import compilation_cache_stats
 
-    budget = args.budget or (30.0 if args.smoke else 120.0)
-    big_T, big_K = 512, 32
-    matched = [16, 64] if args.smoke else [16, 64, 128]
-
+    matched = [16, 64] if smoke else [16, 64, 128]
     clear_plan_cache()
     results = {
         "bench": "enum_ve",
         "jax": jax.__version__,
         "jax_backend": jax.default_backend(),
         "kernel_backend_env": os.environ.get("REPRO_KERNEL_BACKEND", "auto (reference off-TPU)"),
-        "smoke": bool(args.smoke),
+        "smoke": bool(smoke),
         "chain": [],
     }
 
-    print(f"# contraction level: pairwise vs planned dispatch (K={big_K})")
+    print(f"# contraction level: pairwise vs planned dispatch (K={BIG_K})")
     print(f"{'T':>5} {'dispatch':>9} {'cold_s':>9} {'steady_ms':>10}")
     steady = {}
     for T in matched:
         for dispatch in ("pairwise", "auto"):
-            r = time_contract(T, big_K, dispatch)
+            r = time_contract(T, BIG_K, dispatch)
             results["chain"].append(r)
             steady[(T, dispatch)] = r["steady_ms"]
             print(f"{T:>5} {dispatch:>9} {r['cold_s']:>9.2f} {r['steady_ms']:>10.2f}")
@@ -276,44 +282,17 @@ def main(argv=None):
             f"{auto_ms:.3f}ms vs {pair_ms:.3f}ms"
         )
 
-    # the acceptance point: T=512 — dispatch runs inline, pairwise gets a
-    # budgeted subprocess (its XLA compile alone exceeds any sane budget).
-    # The budget scales with the machine: at least 2x the measured hmm_scan
-    # wall time, so a slow CI runner can't fail the comparison spuriously.
-    scan512 = time_contract(big_T, big_K, "auto")
+    # the acceptance point: T=512 on the planned path (the parent races it
+    # against the budgeted pairwise worker)
+    scan512 = time_contract(BIG_T, BIG_K, "auto")
     results["chain"].append(scan512)
-    print(f"{big_T:>5} {'auto':>9} {scan512['cold_s']:>9.2f} {scan512['steady_ms']:>10.2f}")
-    budget = max(budget, 2.0 * scan512["cold_s"])
-    pair512 = time_contract_budgeted(big_T, big_K, "pairwise", budget_s=budget)
-    results["chain"].append(pair512)
-    if pair512.get("timed_out"):
-        print(f"{big_T:>5} {'pairwise':>9} >{budget:>8.0f} {'(budget exceeded)':>10}")
-        pairwise_total = budget
-    elif pair512.get("failed"):
-        raise RuntimeError(f"pairwise worker failed: {pair512['stderr']}")
-    else:
-        print(f"{big_T:>5} {'pairwise':>9} {pair512['cold_s']:>9.2f} {pair512['steady_ms']:>10.2f}")
-        pairwise_total = pair512["cold_s"]
-    scan_total = scan512["cold_s"]
-    results["winner"] = {
-        "T": big_T,
-        "K": big_K,
-        "planned_total_s": scan_total,
-        "pairwise_total_s_lower_bound": pairwise_total,
-        "speedup_lower_bound": round(pairwise_total / scan_total, 2),
-    }
-    assert scan_total < pairwise_total, (
-        f"planned path ({scan_total:.1f}s) did not beat pairwise "
-        f"({pairwise_total:.1f}s lower bound) at T={big_T}, K={big_K}"
-    )
-    print(f"planned path beats pairwise at T={big_T}, K={big_K}: "
-          f">= {results['winner']['speedup_lower_bound']}x")
+    print(f"{BIG_T:>5} {'auto':>9} {scan512['cold_s']:>9.2f} {scan512['steady_ms']:>10.2f}")
     # the compile-time war: cold trace+compile+run of the T=512 chain must
     # stay within half of the pre-planner 27.7s committed baseline (env
     # override for slow hosted runners)
     cold_budget = float(os.environ.get("REPRO_BENCH_COLD_BUDGET_S", "13.85"))
-    assert scan_total <= cold_budget, (
-        f"T={big_T} cold compile {scan_total:.1f}s exceeds the "
+    assert scan512["cold_s"] <= cold_budget, (
+        f"T={BIG_T} cold compile {scan512['cold_s']:.1f}s exceeds the "
         f"{cold_budget:.1f}s budget (REPRO_BENCH_COLD_BUDGET_S)"
     )
 
@@ -322,7 +301,7 @@ def main(argv=None):
     warm_stats0 = plan_cache_stats()
     replan_T = matched[-1]
     t0 = time.perf_counter()
-    r2 = time_contract(replan_T, big_K, "auto")
+    r2 = time_contract(replan_T, BIG_K, "auto")
     warm_stats = plan_cache_stats()
     hits = warm_stats["hits"] - warm_stats0["hits"]
     misses = warm_stats["misses"] - warm_stats0["misses"]
@@ -330,7 +309,7 @@ def main(argv=None):
     results["plan_cache"] = {
         "bench": "replan",
         "T": replan_T,
-        "K": big_K,
+        "K": BIG_K,
         "hits": hits,
         "misses": misses,
         "replan_ms": replan_ms,
@@ -354,11 +333,66 @@ def main(argv=None):
     # planner's ~18-edge scan crossover (smoke: 19 edges, full: 23), so the
     # model level genuinely exercises the fused chain lowering
     results["models"] = model_stage(
-        hmm_T=20 if args.smoke else 24,
-        hmm_K=4 if args.smoke else 8,
-        gmm_N=512 if args.smoke else 4096,
-        steps=8 if args.smoke else 25,
+        hmm_T=20 if smoke else 24,
+        hmm_K=4 if smoke else 8,
+        gmm_N=512 if smoke else 4096,
+        steps=8 if smoke else 25,
     )
+    return results
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--smoke", action="store_true", help="CI-sized run")
+    ap.add_argument("--json", default=str(REPO / "BENCH_enum.json"), help="output path")
+    ap.add_argument("--budget", type=float, default=None,
+                    help="wall-clock budget (s) for the pairwise T=512 attempt")
+    ap.add_argument("--worker", nargs=3, metavar=("T", "K", "DISPATCH"),
+                    help=argparse.SUPPRESS)  # internal: budgeted subprocess entry
+    ap.add_argument("--stage", action="store_true",
+                    help=argparse.SUPPRESS)  # internal: the planned-path worker
+    args = ap.parse_args(argv)
+
+    if args.worker:
+        T, K, dispatch = int(args.worker[0]), int(args.worker[1]), args.worker[2]
+        print(json.dumps(time_contract(T, K, dispatch, reps=5)))
+        return 0
+    if args.stage:
+        print(json.dumps(planned_stage(args.smoke)))
+        return 0
+
+    results = _worker(["--stage"] + (["--smoke"] if args.smoke else []))
+    scan512 = results["chain"][-1]
+
+    # pairwise gets a budgeted worker of its own (its XLA compile alone
+    # exceeds any sane budget). The budget scales with the machine: at least
+    # 2x the measured hmm_scan wall time, so a slow CI runner can't fail the
+    # comparison spuriously.
+    budget = max(args.budget or (30.0 if args.smoke else 120.0), 2.0 * scan512["cold_s"])
+    pair512 = time_contract_budgeted(BIG_T, BIG_K, "pairwise", budget_s=budget)
+    results["chain"].append(pair512)
+    if pair512.get("timed_out"):
+        print(f"{BIG_T:>5} {'pairwise':>9} >{budget:>8.0f} {'(budget exceeded)':>10}")
+        pairwise_total = budget
+    elif pair512.get("failed"):
+        raise RuntimeError(f"pairwise worker failed: {pair512['stderr']}")
+    else:
+        print(f"{BIG_T:>5} {'pairwise':>9} {pair512['cold_s']:>9.2f} {pair512['steady_ms']:>10.2f}")
+        pairwise_total = pair512["cold_s"]
+    scan_total = scan512["cold_s"]
+    results["winner"] = {
+        "T": BIG_T,
+        "K": BIG_K,
+        "planned_total_s": scan_total,
+        "pairwise_total_s_lower_bound": pairwise_total,
+        "speedup_lower_bound": round(pairwise_total / scan_total, 2),
+    }
+    assert scan_total < pairwise_total, (
+        f"planned path ({scan_total:.1f}s) did not beat pairwise "
+        f"({pairwise_total:.1f}s lower bound) at T={BIG_T}, K={BIG_K}"
+    )
+    print(f"planned path beats pairwise at T={BIG_T}, K={BIG_K}: "
+          f">= {results['winner']['speedup_lower_bound']}x")
 
     Path(args.json).write_text(json.dumps(results, indent=2) + "\n")
     print(f"\nwrote {args.json}")

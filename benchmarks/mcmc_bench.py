@@ -50,8 +50,6 @@ MIN_SPEEDUP = 2.0
 
 def run_case(mode: str, chains: int, warmup: int, samples: int) -> dict:
     import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
     import jax.numpy as jnp
 
     from repro import distributions as dist

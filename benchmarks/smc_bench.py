@@ -28,7 +28,6 @@ Usage:
 import argparse
 import json
 import math
-import os
 import sys
 import time
 from pathlib import Path
@@ -65,13 +64,12 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
-
-    jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
     import jax.numpy as jnp
     import numpy as np
 
     from repro import distributions as dist
     from repro.core import primitives as P
+    from repro.distributed import make_mesh
     from repro.infer import SMC
 
     T = 16 if args.smoke else 64
@@ -98,7 +96,7 @@ def main() -> int:
     log_z_exact = exact_log_z(ys)
     print(f"T={T} observations, exact log Z = {log_z_exact:.4f}")
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     rows = []
     for n in grid:
         smc = SMC(model_init, model_step, num_particles=n)

@@ -18,6 +18,7 @@ import jax
 from repro import distributions as dist
 from repro import optim
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import SVI, AutoNormal, Trace_ELBO
 
 N_FULL = 4096
@@ -33,7 +34,7 @@ def model(data):
 
 def main(steps: int = 50, particles: int = 8, log=print):
     data = 1.5 + 0.7 * jax.random.normal(jax.random.PRNGKey(0), (N_FULL,))
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     guide = AutoNormal(model)
     svi = SVI(model, guide, optim.Adam(0.05), Trace_ELBO(num_particles=particles), mesh=mesh)
     state = svi.init(jax.random.PRNGKey(1), data)

@@ -13,6 +13,7 @@ from .sharding import (
     cache_shardings,
     constrain_activation,
     constrain_leading_dim,
+    make_mesh,
     param_shardings,
     replicated,
     shard_batch,
@@ -31,6 +32,7 @@ __all__ = [
     "constrain_leading_dim",
     "dequantize_int8",
     "dequantize_tree",
+    "make_mesh",
     "param_shardings",
     "plan_remesh",
     "quantize_int8",

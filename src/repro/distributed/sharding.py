@@ -23,7 +23,7 @@ import re
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
 
@@ -229,11 +229,24 @@ def shard_batch(tree: Any, mesh: Mesh) -> Any:
     return jax.tree.map(lambda x: constrain_leading_dim(x, mesh, dp), tree)
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], *, devices=None) -> Mesh:
+    """The repo's one mesh constructor: every axis is ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which
+    ``with_sharding_constraint`` (the `constrain_leading_dim` path every
+    ``mesh=`` engine takes) refuses; Auto axes leave placement to GSPMD
+    propagation, which is what the sharding rules here are written for."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
+
+
 def default_mesh(axis_name: str = "data") -> Mesh:
     """1-D mesh over all local devices — the default for embarrassingly
     data-parallel workloads (MCMC chain sharding) where no TP axis is needed.
     `batch_axes` resolves it like any other mesh with a 'data' axis."""
-    return jax.make_mesh((jax.device_count(),), (axis_name,))
+    return make_mesh((jax.device_count(),), (axis_name,))
 
 
 def shard_chains(tree: Any, mesh: Mesh) -> Any:

@@ -312,7 +312,7 @@ class Resample(Program):
         do = ess_in < self.ess_threshold * n
         if self._resolved_method() == "systematic":
             u0 = jax.random.uniform(key_r)
-            ancestors = ops.resample(lw, u0)
+            ancestors = ops.resample(lw, u0, mesh=mesh, axis=particle_axis)
         else:
             ancestors = jnp.sort(jax.random.categorical(key_r, lw, shape=(n,)))
         idx = jnp.where(do, ancestors, jnp.arange(n))

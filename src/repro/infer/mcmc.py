@@ -463,7 +463,7 @@ class HMC:
 
     def fused_sample_step(
         self, state: FlatHMCState, pe_flat, warmup_len: int = 0,
-        backend: Optional[str] = None,
+        backend: Optional[str] = None, mesh=None,
     ) -> FlatHMCState:
         """One batched HMC transition for all C chains via `ops.leapfrog`.
         The trajectory length is shared across chains — fixed at
@@ -488,7 +488,7 @@ class HMC:
         n_c = jnp.broadcast_to(n, (C,)).astype(jnp.int32)
         z_new, r_new, pe_new = ops.leapfrog(
             state.z, r, inv_b, eps_c, n_c, pe_flat,
-            max_steps=self.max_num_steps, backend=backend,
+            max_steps=self.max_num_steps, backend=backend, mesh=mesh,
         )
         energy1 = pe_new + 0.5 * jnp.sum(inv_b * r_new * r_new, axis=-1)
         delta = energy0 - energy1
@@ -690,7 +690,7 @@ class NUTS(HMC):
     # -- fused batched path: tree building vectorized across the chain axis --
     def fused_sample_step(
         self, state: FlatHMCState, pe_flat, warmup_len: int = 0,
-        backend: Optional[str] = None,
+        backend: Optional[str] = None, mesh=None,
     ) -> FlatHMCState:
         """One batched NUTS transition: the iterative doubling loop runs ONCE
         for the whole (C, D) batch with per-chain direction draws and active
@@ -737,7 +737,7 @@ class NUTS(HMC):
                 active = ~stop & ~s_turn & ~s_div
                 z_n, r_n, pe_n = ops.leapfrog(
                     z_e, r_e, inv_b, eps * dirs, active.astype(jnp.int32),
-                    pe_flat, max_steps=1, backend=backend,
+                    pe_flat, max_steps=1, backend=backend, mesh=mesh,
                 )
                 e_n = pe_n + 0.5 * jnp.sum(inv_b * r_n * r_n, axis=-1)
                 delta = e_n - energy0
@@ -1076,7 +1076,7 @@ class MCMC:
             state = shard_state(state)
 
             def step(s):
-                return kernel.fused_sample_step(s, pe_flat, W, backend=backend)
+                return kernel.fused_sample_step(s, pe_flat, W, backend=backend, mesh=mesh)
 
             def warmup_body(s, i):
                 s = step(s)

@@ -691,7 +691,9 @@ def gaussian_marginals(
 
     zero = {n: jnp.zeros((gauss.widths[n],), jnp.float32) for n in query}
     means = jax.grad(log_z)(zero)
-    covs = jax.jacfwd(jax.grad(log_z))(zero)
+    # reverse over reverse: the Gaussian kernels carry custom VJPs, which
+    # forward mode (jacfwd) cannot differentiate
+    covs = jax.jacrev(jax.grad(log_z))(zero)
     out: Dict[str, Tuple[jax.Array, jax.Array]] = {}
     for n in query:
         m, C = means[n], covs[n][n]

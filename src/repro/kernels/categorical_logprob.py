@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .compiler import compiler_params
+
 NEG_INF = -1e30
 
 
@@ -85,7 +87,7 @@ def categorical_logprob_flat(
             pltpu.VMEM((bt,), jnp.float32),  # running sum
             pltpu.VMEM((bt,), jnp.float32),  # target logit
         ],
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(logits, tokens[:, None].astype(jnp.int32))
     return out[:T, 0]

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .compiler import compiler_params
+
 NEG_INF = -1e30
 
 
@@ -97,6 +99,6 @@ def flash_attention_gqa(
             pltpu.VMEM((bq,), jnp.float32),      # running denom
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(q, k, v)

@@ -40,7 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .compiler import compiler_params
 
 LOG_2PI = 1.8378770664093453
 
@@ -183,7 +184,7 @@ def gaussian_combine_pairs(f, g, *, block_b: int = 256, interpret: bool = False)
         in_specs=specs + specs,
         out_specs=tuple(specs),
         out_shape=(mat, mat, mat, vec, vec, sc),
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=compiler_params("parallel"),
         interpret=interpret,
     )(*inputs)
 

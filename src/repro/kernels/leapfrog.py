@@ -50,7 +50,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .compiler import compiler_params
 
 
 def shared_grad_leapfrog(z, r, inv_mass, eps, num_steps, max_steps, vg_fn):
@@ -181,9 +182,7 @@ def leapfrog_fused(
             jax.ShapeDtypeStruct((Cp, D), jnp.float32),
             jax.ShapeDtypeStruct((Cp, 1), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
     )(
         z,

@@ -90,6 +90,29 @@ def backend_support_matrix() -> dict:
     return {op: {b: b in sup for b in BACKENDS} for op, sup in _SUPPORT.items()}
 
 
+def _on_mesh(fn, args, split, mesh, axis=None):
+    """Call the Pallas kernel call `fn(*args)` with its batch on `mesh`.
+
+    XLA cannot partition a Mosaic kernel, so under a mesh the call runs per
+    device inside `shard_map`: the args flagged in `split` have their leading
+    dim split over `axis` (default: the mesh's data axes) when it divides the
+    axis size, every other arg is replicated, and the outputs come back split
+    the same way. Without a mesh this is just ``fn(*args)``."""
+    if mesh is None:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+
+    from ..distributed.sharding import batch_axes, data_axis_size
+
+    axis = batch_axes(mesh) if axis is None else axis
+    n = next(x.shape[0] for x, s in zip(args, split) if s)
+    spec = P(axis) if n % data_axis_size(mesh, axis) == 0 else P()
+    in_specs = tuple(spec if s else P() for s in split)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False
+    )(*args)
+
+
 # -- flash attention ---------------------------------------------------------
 
 
@@ -97,6 +120,14 @@ def backend_support_matrix() -> dict:
 def _flash_attention(q, k, v, *, causal, block_q, block_k, backend):
     if backend == "reference":
         return ref.flash_attention_ref(q, k, v, causal=causal)
+    return _flash_attention_kernel(q, k, v, causal, block_q, block_k, backend)
+
+
+# The LM train step differentiates through attention, and a pallas_call has
+# no AD rule of its own: fused forward, reference backward (the same
+# function, so the same gradient) — as for the semiring and Gaussian ops.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_kernel(q, k, v, causal, block_q, block_k, backend):
     B, H, Sq, d = q.shape
     K, Skv = k.shape[1], k.shape[2]
     g = H // K
@@ -108,6 +139,18 @@ def _flash_attention(q, k, v, *, causal, block_q, block_k, backend):
         interpret=(backend == "interpret"),
     )
     return out.reshape(B, K, g, Sq, d).reshape(B, H, Sq, d)
+
+
+def _flash_attention_kernel_fwd(q, k, v, causal, block_q, block_k, backend):
+    return _flash_attention_kernel(q, k, v, causal, block_q, block_k, backend), (q, k, v)
+
+
+def _flash_attention_kernel_bwd(causal, block_q, block_k, backend, res, g):
+    _, vjp = jax.vjp(functools.partial(ref.flash_attention_ref, causal=causal), *res)
+    return vjp(g)
+
+
+_flash_attention_kernel.defvjp(_flash_attention_kernel_fwd, _flash_attention_kernel_bwd)
 
 
 def flash_attention(
@@ -241,7 +284,7 @@ def semiring_matmul(
     b,
     *,
     semiring: str = "logsumexp",
-    block: int = 64,
+    block: int = 128,
     backend: Optional[str] = None,
 ):
     """Log-space semiring matmul: ``out[..., i, j] = ⊕_k a[..., i, k] + b[..., k, j]``
@@ -287,8 +330,8 @@ def _resample_counts_kernel_bwd(block, backend, res, g):
 _resample_counts_kernel.defvjp(_resample_counts_kernel_fwd, _resample_counts_kernel_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "backend"))
-def _resample(log_weights, u0, *, block, backend):
+@functools.partial(jax.jit, static_argnames=("block", "backend", "mesh", "axis"))
+def _resample(log_weights, u0, *, block, backend, mesh, axis):
     if backend == "reference":
         return ref.systematic_resample_ref(log_weights, u0)
     n = log_weights.shape[-1]
@@ -296,10 +339,17 @@ def _resample(log_weights, u0, *, block, backend):
     # kernel backends count the exact same comparisons bit-for-bit
     c = ref.resample_inputs_ref(log_weights)
     u = ref.resample_grid_ref(u0, n)
-    return _resample_counts_kernel(c, u, block, backend)
+    # each device counts its slice of the grid against the whole cumsum
+    return _on_mesh(
+        lambda c, u: _resample_counts_kernel(c, u, block, backend),
+        (c, u), (False, True), mesh, axis,
+    )
 
 
-def resample(log_weights, u0, *, block: int = 256, backend: Optional[str] = None):
+def resample(
+    log_weights, u0, *, block: int = 256, backend: Optional[str] = None,
+    mesh=None, axis=None,
+):
     """Systematic resampling: ancestor indices for an SMC particle population.
 
     log_weights: (N,) unnormalized particle log-weights (``-inf`` = dead
@@ -308,7 +358,9 @@ def resample(log_weights, u0, *, block: int = 256, backend: Optional[str] = None
     grid u_i = (u0 + i)/N — one random number per resample event is what
     makes systematic resampling lower-variance than multinomial. Returns (N,)
     int32 ancestor indices, sorted (a free by-product of the sorted-grid
-    formulation). Gradients: zero (see `_resample_counts_kernel`)."""
+    formulation). Gradients: zero (see `_resample_counts_kernel`).
+    ``mesh``/``axis``: the mesh the particle axis is sharded on (see
+    `_on_mesh`); ``axis`` defaults to the mesh's data axes."""
     log_weights = jnp.asarray(log_weights)
     if log_weights.ndim != 1:
         raise ValueError(
@@ -317,7 +369,10 @@ def resample(log_weights, u0, *, block: int = 256, backend: Optional[str] = None
         )
     if log_weights.shape[0] < 1:
         raise ValueError("need at least one particle to resample")
-    return _resample(log_weights, u0, block=block, backend=resolve_backend(backend))
+    return _resample(
+        log_weights, u0, block=block, backend=resolve_backend(backend),
+        mesh=mesh, axis=axis,
+    )
 
 
 # -- fused HMC leapfrog (MCMC hot path) ---------------------------------------
@@ -334,6 +389,7 @@ def leapfrog(
     max_steps: int,
     block_chains: int = 8,
     backend: Optional[str] = None,
+    mesh=None,
 ):
     """Run a batch of leapfrog trajectories in one fused program.
 
@@ -355,6 +411,9 @@ def leapfrog(
     No AD rule on purpose: MCMC never differentiates its own transition, and
     ``jax.grad`` through this op should fail loudly, not silently pick an
     unfused path.
+
+    ``mesh``: the mesh the chain axis is sharded on; the kernel then runs per
+    device on its chains (see `_on_mesh`).
     """
     backend = resolve_backend(backend)
     if backend == "reference":
@@ -362,19 +421,21 @@ def leapfrog(
             z, r, inv_mass, step_size, num_steps, potential_fn,
             max_steps=max_steps,
         )
-    closed = jax.make_jaxpr(jax.value_and_grad(potential_fn))(z[0])
-    return leapfrog_fused(
-        z,
-        r,
-        inv_mass,
-        step_size,
-        num_steps,
-        closed.consts,
-        jaxpr=closed.jaxpr,
-        max_steps=max_steps,
-        block_chains=block_chains,
-        interpret=(backend == "interpret"),
+    # traced on an unsharded (D,) shape: the jaxpr is replayed per device
+    closed = jax.make_jaxpr(jax.value_and_grad(potential_fn))(
+        jax.ShapeDtypeStruct(z.shape[1:], z.dtype)
     )
+    consts = [jnp.asarray(c) for c in closed.consts]
+
+    def fused(z, r, inv_mass, step_size, num_steps, *consts):
+        return leapfrog_fused(
+            z, r, inv_mass, step_size, num_steps, consts,
+            jaxpr=closed.jaxpr, max_steps=max_steps, block_chains=block_chains,
+            interpret=(backend == "interpret"),
+        )
+
+    args = (z, r, inv_mass, step_size, num_steps, *consts)
+    return _on_mesh(fused, args, (True,) * 5 + (False,) * len(consts), mesh)
 
 
 # -- information-form Gaussian combine / Kalman scan (Gaussian semiring) ------
@@ -565,7 +626,7 @@ def hmm_scan(
     *,
     semiring: str = "logsumexp",
     cumulative: bool = False,
-    block: int = 64,
+    block: int = 128,
     backend: Optional[str] = None,
 ):
     """Eliminate a Markov chain of K x K log-factors in O(log T) depth.

@@ -59,7 +59,10 @@ def semiring_matmul_ref(a, b, *, semiring: str = "logsumexp") -> jax.Array:
     bm = jnp.max(b, axis=-2, keepdims=True)  # (..., 1, N)
     am_s = jnp.where(jnp.isfinite(am), am, 0.0)  # fully -inf rows stay -inf, not nan
     bm_s = jnp.where(jnp.isfinite(bm), bm, 0.0)
-    p = jnp.einsum("...mk,...kn->...mn", jnp.exp(a - am_s), jnp.exp(b - bm_s))
+    p = jnp.einsum(
+        "...mk,...kn->...mn", jnp.exp(a - am_s), jnp.exp(b - bm_s),
+        precision=jax.lax.Precision.HIGHEST,
+    )
     return jnp.log(p) + am_s + bm_s
 
 

@@ -28,7 +28,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .compiler import compiler_params
 
 
 def _count_kernel(c_ref, u_ref, o_ref, *, nc: int):
@@ -75,9 +76,7 @@ def resample_counts_tiled(
         ],
         out_specs=pl.BlockSpec((1, bu), lambda iu, jc: (0, iu)),
         out_shape=jax.ShapeDtypeStruct((1, mp), jnp.int32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(c.reshape(np_, 1), u.reshape(1, mp))
     return out[0, :m]

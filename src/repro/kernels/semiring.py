@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .compiler import compiler_params
+
 NEG_INF = -1e30  # finite -inf stand-in: exp(NEG_INF - anything_real) == 0 in f32
 
 SEMIRINGS = ("logsumexp", "max")
@@ -65,8 +67,11 @@ def _semiring_matmul_kernel(a_ref, b_ref, o_ref, m_ref, s_ref, *, nk: int, semir
         # guard fully-masked (-inf) rows/cols: exp(-inf - -inf) would be nan
         am_s = jnp.where(jnp.isfinite(am), am, 0.0)
         bm_s = jnp.where(jnp.isfinite(bm), bm, 0.0)
+        # full f32 passes: a default-precision TPU matmul rounds the
+        # probabilities to bf16, which exact inference cannot afford
         p = jnp.dot(
-            jnp.exp(a - am_s), jnp.exp(b - bm_s), preferred_element_type=jnp.float32
+            jnp.exp(a - am_s), jnp.exp(b - bm_s),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
         )
         m_cur = am_s + bm_s  # (bm, bn) tile max bound
         m_prev, s_prev = m_ref[...], s_ref[...]
@@ -85,14 +90,22 @@ def _semiring_matmul_kernel(a_ref, b_ref, o_ref, m_ref, s_ref, *, nk: int, semir
             o_ref[...] = m_ref[...]
 
 
+def _tile(dim: int, block: int, align: int) -> int:
+    """The tile for one dim: the whole dim when it fits in `block`, else
+    `block` rounded up to a multiple of `align` (the dim is padded to it)."""
+    if dim <= block:
+        return dim
+    return -(-block // align) * align
+
+
 def semiring_matmul_tiled(
     a: jax.Array,  # (M, K) log-factor
     b: jax.Array,  # (K, N) log-factor
     *,
     semiring: str = "logsumexp",
-    block_m: int = 64,
-    block_n: int = 64,
-    block_k: int = 64,
+    block_m: int = 128,
+    block_n: int = 128,
+    block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """out[i, j] = ⊕_k a[i, k] + b[k, j] over the (⊕, +) log-space semiring.
@@ -107,7 +120,10 @@ def semiring_matmul_tiled(
     K2, N = b.shape
     if K != K2:
         raise ValueError(f"contracting dims disagree: a is {a.shape}, b is {b.shape}")
-    bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
+    # Mosaic tiles a block's last two dims by (8, 128): each must be a
+    # multiple of that or the whole array dim. K is a lane dim of `a` and N
+    # of `b`/out, so their tiles round up to 128; M only needs 8.
+    bm, bn, bk = _tile(M, block_m, 8), _tile(N, block_n, 128), _tile(K, block_k, 128)
     Mp, Np, Kp = -(-M // bm) * bm, -(-N // bn) * bn, -(-K // bk) * bk
     # K-padding must be the exact ⊕-identity: -inf for max-plus (NEG_INF would
     # leak a finite floor into fully -inf entries); the finite stand-in is fine
@@ -133,9 +149,7 @@ def semiring_matmul_tiled(
             pltpu.VMEM((bm, bn), jnp.float32),  # running ⊕-max
             pltpu.VMEM((bm, bn), jnp.float32),  # running shifted sum (logsumexp only)
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(a, b)
     return out[:M, :N]

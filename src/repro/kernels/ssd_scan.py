@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .compiler import compiler_params
+
 
 def _ssd_kernel(x_ref, dA_ref, dt_ref, b_ref, c_ref, o_ref, state_ref, *, Q: int):
     ic = pl.program_id(2)
@@ -34,34 +36,46 @@ def _ssd_kernel(x_ref, dA_ref, dt_ref, b_ref, c_ref, o_ref, state_ref, *, Q: int
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)    # (Q, P)
-    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (Q,)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)  # (Q,)
+    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (Q, 1)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)  # (Q, 1)
     Bm = b_ref[0, 0].astype(jnp.float32)      # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)      # (Q, N)
 
-    cum = jnp.cumsum(dA)  # (Q,)
-
-    # --- intra-chunk: (L o C B^T) (dt*x) on the MXU ---
-    diff = cum[:, None] - cum[None, :]
+    # Mosaic has no cumsum: the within-chunk prefix sums are matmuls with a
+    # triangular 0/1 mask, at full f32 precision so they stay exact sums.
+    # The per-step vectors ride as (Q, 1) columns; their (1, Q) row forms
+    # come from the same contraction with the lhs transposed.
     row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(row >= col, jnp.exp(diff), 0.0)
+    lower = (row >= col).astype(jnp.float32)
+    exact = jax.lax.Precision.HIGHEST
+
+    def rows(v, m):  # (Q, 1) column, (Q, Q) mask -> (1, Q) row of v^T m
+        return jax.lax.dot_general(v, m, (((0,), (0,)), ((), ())),
+                                   precision=exact, preferred_element_type=jnp.float32)
+
+    cum = jnp.dot(lower, dA, precision=exact, preferred_element_type=jnp.float32)  # (Q,1)
+    cum_t = rows(dA, lower.T)                            # (1, Q)
+    dt_t = rows(dt, (row == col).astype(jnp.float32))    # (1, Q)
+
+    # --- intra-chunk: (L o C B^T) (dt*x) on the MXU ---
+    L = jnp.where(row >= col, jnp.exp(cum - cum_t), 0.0)
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q,Q)
-    M = CB * L * dt[None, :]
+    M = CB * L * dt_t
     y = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q,P)
 
     # --- inter-chunk: y += (C * exp(cum)) @ state_in ---
     state_in = state_ref[...]
-    y = y + jax.lax.dot_general(Cm * jnp.exp(cum)[:, None], state_in,
+    y = y + jax.lax.dot_general(Cm * jnp.exp(cum), state_in,
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
     # --- state update: state = exp(cum_Q) * state_in + B^T (dt * decay * x) ---
-    decay_to_end = jnp.exp(cum[-1] - cum)  # (Q,)
-    wx = x * (dt * decay_to_end)[:, None]  # (Q,P)
-    new_state = jnp.exp(cum[-1]) * state_in + jax.lax.dot_general(
+    last = jnp.sum(dA)                        # cum[Q-1], as a scalar
+    wx = x * (dt * jnp.exp(last - cum))       # (Q,P)
+    new_state = jnp.exp(last) * state_in + jax.lax.dot_general(
         Bm, wx, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (N,P)
     state_ref[...] = new_state
@@ -85,14 +99,14 @@ def ssd_scan_chunked(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q, 1), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, Q, 1), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(x, dA, dt, Bm, Cm)
+    )(x, dA[..., None], dt[..., None], Bm, Cm)

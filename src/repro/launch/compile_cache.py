@@ -2,19 +2,19 @@
 
 The contraction planner cuts what a cold trace *builds*; this module makes
 the XLA compile of what remains a one-time cost per (program, jaxlib,
-backend) by pointing ``jax_compilation_cache_dir`` at a directory that
-survives the process — locally under the user's cache dir, in CI via
-``actions/cache``. The second run of the same launch/serve/bench program
+backend) by pointing JAX's persistent compilation cache at a directory that
+survives the process. The second run of the same launch/serve/bench program
 then deserializes executables instead of recompiling them.
 
-Knobs (all optional):
+Where the cache lives:
 
-* ``REPRO_COMPILATION_CACHE_DIR`` — cache directory. ``0``/``off`` disables
-  persistence entirely; unset falls back to
-  ``$XDG_CACHE_HOME/repro/xla-cache`` (or ``~/.cache/repro/xla-cache``).
-* ``REPRO_COMPILATION_CACHE_MIN_COMPILE_S`` — only persist programs whose
-  compile took at least this long (default ``0.5``; tiny programs aren't
-  worth the disk round-trip).
+* ``JAX_COMPILATION_CACHE_DIR``, when set — JAX reads it itself, and this
+  module sets no other directory;
+* otherwise the fixed path ``<checkout>/.xla-cache`` (git-ignored). The
+  directory is part of each entry's key, so it must not move between runs.
+
+``REPRO_COMPILATION_CACHE_MIN_COMPILE_S`` (default ``0.5``) keeps programs
+that compiled faster than that out of the cache.
 """
 from __future__ import annotations
 
@@ -26,19 +26,15 @@ import jax
 
 from .. import settings
 
-_OFF = ("0", "false", "off", "none")
+CHECKOUT = Path(__file__).resolve().parents[3]
 
 
-def cache_dir() -> Optional[Path]:
-    """Resolved compilation-cache directory, or None when disabled."""
-    if settings.is_set("REPRO_COMPILATION_CACHE_DIR"):
-        env = settings.get_str("REPRO_COMPILATION_CACHE_DIR")
-        if env.strip().lower() in _OFF:
-            return None
+def cache_dir() -> Path:
+    """The persistent compilation-cache directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
         return Path(env).expanduser()
-    base = os.environ.get("XDG_CACHE_HOME")
-    root = Path(base).expanduser() if base else Path.home() / ".cache"
-    return root / "repro" / "xla-cache"
+    return CHECKOUT / ".xla-cache"
 
 
 def enable_compilation_cache() -> Optional[Path]:
@@ -46,14 +42,13 @@ def enable_compilation_cache() -> Optional[Path]:
 
     Idempotent and safe to call before any JAX computation (launch mains call
     it right after argument parsing). Returns the directory in use, or None
-    when persistence is disabled. Never raises: an unwritable directory just
-    means cold compiles stay cold."""
+    when it cannot be used. Never raises: an unwritable directory just means
+    cold compiles stay cold."""
     path = cache_dir()
-    if path is None:
-        return None
     try:
         path.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", str(path))
         # persist anything that took real compile time; leave trivial
         # executables out so the cache stays small and the hit path hot
         jax.config.update(
@@ -71,8 +66,8 @@ def compilation_cache_stats() -> Dict:
     """Entry count + on-disk bytes of the persistent cache directory (the
     bench stage prints this so the warm path is visibly exercised)."""
     path = cache_dir()
-    if path is None or not path.is_dir():
-        return {"dir": str(path) if path else None, "entries": 0, "bytes": 0}
+    if not path.is_dir():
+        return {"dir": str(path), "entries": 0, "bytes": 0}
     files = [p for p in path.rglob("*") if p.is_file()]
     return {
         "dir": str(path),

@@ -43,6 +43,9 @@ from .hlo_cost import analyze_hlo
 from .mesh import make_production_mesh
 from .roofline import Roofline, model_flops
 
+# the production mesh is a v5e pod; the placeholder CPU devices only lower
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _sds(tree):
     return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
@@ -202,6 +205,7 @@ def run_cell(
         arch=arch,
         shape=shape,
         mesh=mesh_name,
+        device_kind=TARGET_DEVICE_KIND,
         chips=chips,
         flops_per_device=hc.flops,
         bytes_per_device=hc.bytes,

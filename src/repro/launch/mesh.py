@@ -4,7 +4,7 @@ Kept as functions — importing this module never touches jax device state.
 """
 from __future__ import annotations
 
-import jax
+from ..distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,9 +13,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     extends data parallelism across the DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for CPU smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -1,14 +1,13 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Hardware model (assignment constants, TPU v5e-class):
-    peak bf16 compute : 197 TFLOP/s per chip
-    HBM bandwidth     : 819 GB/s per chip
-    ICI link bandwidth: ~50 GB/s per link
+Hardware model: the per-chip peaks of the device the program targets, keyed
+by ``device_kind`` as JAX reports it (`PEAKS`). A device that is not in the
+table is an error, not a default.
 
 Terms (per step, in seconds):
-    compute    = HLO_FLOPs_total    / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes_total    / (chips * HBM_BW)
-    collective = collective_bytes   / (chips * ICI_BW)
+    compute    = HLO_FLOPs_total    / (chips * peak_flops)
+    memory     = HLO_bytes_total    / (chips * hbm_bw)
+    collective = collective_bytes   / (chips * ici_bw)
 
 `cost_analysis()` on an SPMD-compiled executable reports *per-device*
 numbers; we multiply by `chips` to get totals, so the two conventions
@@ -20,11 +19,32 @@ from __future__ import annotations
 
 import re
 from dataclasses import asdict, dataclass
-from typing import Dict
+from typing import Dict, NamedTuple
 
-PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-HBM_BW = 819e9       # bytes/s per chip
-ICI_BW = 50e9        # bytes/s per link
+
+class Peaks(NamedTuple):
+    flops: float   # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # inter-chip bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of inter-chip interconnect over 4 links (50 GB/s each).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Published per-chip peaks of `device_kind`; raises on an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -82,23 +102,27 @@ class Roofline:
     arch: str
     shape: str
     mesh: str
+    device_kind: str
     chips: int
     flops_per_device: float
     bytes_per_device: float
     collective_bytes_per_device: float
     model_flops: float  # 6*N(_active)*D tokens-based estimate
 
+    def __post_init__(self):
+        self.peaks = peaks_for(self.device_kind)
+
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -118,7 +142,7 @@ class Roofline:
         t = max(self.t_compute, self.t_memory, self.t_collective)
         if t == 0:
             return 0.0
-        return self.model_flops / (self.chips * PEAK_FLOPS * t)
+        return self.model_flops / (self.chips * self.peaks.flops * t)
 
     def to_dict(self) -> dict:
         d = asdict(self)

@@ -51,7 +51,7 @@ class Knob:
 
     name: str
     default: Optional[str]  # None = unset by default
-    kind: str  # "str" | "bool" | "int" | "float" | "path"
+    kind: str  # "str" | "bool" | "int" | "float"
     effect: str  # one-line description (the docs table's "effect" column)
     choices: Optional[Tuple[str, ...]] = None
     deprecated: bool = False
@@ -99,10 +99,6 @@ _KNOB_ROWS = [
          "elimination replans)"),
     Knob("REPRO_ENUM_PLAN_CACHE_SIZE", "256", "int",
          "plan-cache capacity (FIFO eviction)"),
-    Knob("REPRO_COMPILATION_CACHE_DIR", "~/.cache/repro/xla-cache", "path",
-         "persistent XLA compilation-cache dir used by `launch/serve.py`, "
-         "`launch/train.py`, `launch/stream.py`, and the bench stage; "
-         "`0`/`off`/`none` disables"),
     Knob("REPRO_COMPILATION_CACHE_MIN_COMPILE_S", "0.5", "float",
          "only compilations slower than this persist to the cache"),
     Knob("REPRO_SMC_RESAMPLE", "systematic", "str",

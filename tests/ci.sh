@@ -151,7 +151,7 @@ run_bench() {
     # local dir (restored across CI runs via actions/cache) so cold-compile
     # numbers measure *our* trace+lowering cost, not XLA re-optimizing
     # unchanged programs.
-    export REPRO_COMPILATION_CACHE_DIR="${REPRO_COMPILATION_CACHE_DIR:-$PWD/.xla-cache}"
+    export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.xla-cache}"
     python benchmarks/svi_sharded.py --smoke
     python benchmarks/mcmc_chains.py --smoke
     python benchmarks/enum_ve.py --smoke --json BENCH_enum.json

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro import distributions as dist
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import ImportanceSampling, compose, extend, primitive, propose
 from repro.infer import resample as resample_combinator
 from repro.infer.combinators import (
@@ -225,7 +226,7 @@ def test_importance_sampling_accessors():
 
 
 def test_importance_sampling_sharded_matches_vectorized():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     vec = ImportanceSampling(model, guide, num_particles=256)
     sh = ImportanceSampling(model, guide, num_particles=256, mesh=mesh)
     vec.run(jax.random.PRNGKey(6))

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro import distributions as dist
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import (
     HMC,
     MCMC,
@@ -167,10 +168,21 @@ def test_mesh_auto_resolves_to_default_mesh():
 
 
 def test_explicit_mesh_object_accepted():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     mcmc = MCMC(HMC(normal_model), 10, 10, mesh=mesh)
     assert mcmc.mesh is mesh
     assert mcmc.chain_method == "sharded"
+
+
+def test_mesh_helper_axes_are_auto():
+    """`with_sharding_constraint` (every mesh= path) refuses Explicit axes,
+    which bare `jax.make_mesh` now defaults to."""
+    from jax.sharding import AxisType
+
+    from repro.distributed.sharding import default_mesh
+
+    for mesh in (make_mesh((1, 1), ("data", "model")), default_mesh()):
+        assert all(t == AxisType.Auto for t in mesh.axis_types)
 
 
 def test_bad_mesh_string_rejected():
@@ -179,7 +191,7 @@ def test_bad_mesh_string_rejected():
 
 
 def test_chain_method_sharded_with_explicit_mesh_keeps_it():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", FutureWarning)
         mcmc = MCMC(HMC(normal_model), 10, 10, chain_method="sharded", mesh=mesh)
@@ -201,7 +213,7 @@ def test_predictive_num_samples_is_canonical():
 def test_particle_engines_share_mesh_kwarg():
     """`mesh=` means the same thing on every engine: constrain the
     parallel axis (chains or particles) onto the mesh."""
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     ys = jnp.asarray([0.4, 0.2])
     for eng in (
         SMC(ssm_init, ssm_step, num_particles=32, mesh=mesh),

@@ -17,6 +17,7 @@ from repro import distributions as dist
 from repro import optim
 from repro.core import handlers
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import (
     SVI,
     AutoNormal,
@@ -263,7 +264,7 @@ def test_compiles_exactly_once_across_steps():
 
 
 def test_sharded_particles_bit_identical():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     guide = AutoNormal(latent_gmm)
 
     def run(elbo, svi_mesh):

@@ -144,6 +144,23 @@ def test_semiring_matmul_interpret_vs_reference(M, K, N, semiring):
     assert jnp.allclose(got_i, got_r, atol=1e-4)
 
 
+@pytest.mark.parametrize("K", [100, 256])
+@pytest.mark.parametrize("semiring", ["logsumexp", "max"])
+def test_semiring_matmul_lane_tiles(K, semiring):
+    """Past one tile the lane dims (K of `a`, N) tile by multiples of 128, as
+    Mosaic requires; padding with the ⊕-identity keeps ragged K exact."""
+    from repro.kernels.semiring import _tile
+
+    assert _tile(K, 128, 128) in (K, 128)
+    assert _tile(300, 64, 128) == 128 and _tile(300, 64, 8) == 64
+    a = jax.random.normal(KEY, (K, K)) * 3
+    b = jax.random.normal(jax.random.fold_in(KEY, 3), (K, K)) * 3
+    got = semiring_matmul(a, b, semiring=semiring, backend="interpret")
+    want = semiring_matmul(a, b, semiring=semiring, backend="reference")
+    assert jnp.allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert jnp.allclose(got, _naive_semiring_matmul(a, b, semiring), atol=1e-4)
+
+
 @pytest.mark.parametrize("semiring", ["logsumexp", "max"])
 def test_semiring_matmul_batched_broadcast(semiring):
     """Batch dims broadcast: (2,3,8,6) x (3,6,5) -> (2,3,8,5)."""

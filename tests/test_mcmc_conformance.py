@@ -73,6 +73,27 @@ def test_leapfrog_single_step_parity():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
+def test_leapfrog_on_a_mesh_matches_unsharded():
+    """Under a mesh the kernel runs per device (shard_map): Mosaic kernels
+    cannot be partitioned automatically. One device gives identical bits."""
+    from repro.distributed import make_mesh
+
+    pe = _quadratic_pe()
+    C, D = 8, 4
+    z = jax.random.normal(jax.random.PRNGKey(4), (C, D))
+    r = jax.random.normal(jax.random.PRNGKey(5), (C, D))
+    inv_mass = jnp.ones((C, D))
+    eps = jnp.full((C,), 0.1)
+    n = jnp.arange(C, dtype=jnp.int32) % 3
+    mesh = make_mesh((1,), ("data",))
+    local = ops.leapfrog(z, r, inv_mass, eps, n, pe, max_steps=4, backend="interpret")
+    sharded = jax.jit(
+        lambda *a: ops.leapfrog(*a, pe, max_steps=4, backend="interpret", mesh=mesh)
+    )(z, r, inv_mass, eps, n)
+    for a, b in zip(local, sharded):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_leapfrog_multi_step_parity_with_masks():
     """Ragged per-chain step counts (including frozen chains and a negative
     step size) agree between backends; frozen chains pass through exactly."""

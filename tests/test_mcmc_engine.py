@@ -13,6 +13,7 @@ import pytest
 
 from repro import distributions as dist
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import (
     HMC,
     MCMC,
@@ -211,7 +212,7 @@ def test_thinning_shapes():
 
 
 def test_sharded_matches_vectorized_on_one_device_mesh():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     runs = {}
     for method, kw in (("vectorized", {"mesh": None}), ("sharded", {"mesh": mesh})):
         mcmc = MCMC(
@@ -240,7 +241,7 @@ def test_fused_sharded_matches_vectorized_with_kernels(monkeypatch):
     Pallas kernel body enabled (interpret backend): the sharding constraint
     is a layout annotation, never a math change."""
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     runs = {}
     for method, kw in (("vectorized", {"mesh": None}), ("sharded", {"mesh": mesh})):
         mcmc = MCMC(

@@ -163,3 +163,16 @@ def test_jit_and_vmap_compatible(backend):
     vmapped = jax.vmap(lambda w: ops.resample(w, 0.5))(batch)
     assert jnp.array_equal(vmapped[0], direct)
     assert jnp.array_equal(vmapped[1], direct)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "reference"])
+def test_on_a_mesh_matches_unsharded(backend):
+    """Each device counts its slice of the grid against the whole cumsum;
+    on one device that is the unsharded call, bit for bit."""
+    from repro.distributed import make_mesh
+
+    lw = 2.0 * jax.random.normal(jax.random.PRNGKey(7), (512,))
+    mesh = make_mesh((1,), ("data",))
+    local = ops.resample(lw, 0.3, backend=backend)
+    sharded = ops.resample(lw, 0.3, backend=backend, mesh=mesh)
+    assert jnp.array_equal(local, sharded)

@@ -64,10 +64,10 @@ class TestGetters:
         assert settings.get_optional_float("REPRO_SERVE_DEADLINE_MS") == 250.0
 
     def test_is_set(self, monkeypatch):
-        monkeypatch.delenv("REPRO_COMPILATION_CACHE_DIR", raising=False)
-        assert not settings.is_set("REPRO_COMPILATION_CACHE_DIR")
-        monkeypatch.setenv("REPRO_COMPILATION_CACHE_DIR", "/tmp/c")
-        assert settings.is_set("REPRO_COMPILATION_CACHE_DIR")
+        monkeypatch.delenv("REPRO_SERVE_DEADLINE_MS", raising=False)
+        assert not settings.is_set("REPRO_SERVE_DEADLINE_MS")
+        monkeypatch.setenv("REPRO_SERVE_DEADLINE_MS", "250")
+        assert settings.is_set("REPRO_SERVE_DEADLINE_MS")
 
 
 class TestDocsTable:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro import distributions as dist
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import (
     SMC,
     SMCFilter,
@@ -147,7 +148,7 @@ def test_num_traces_stays_one_across_reruns():
 
 
 def test_sharded_matches_vectorized_bit_identical():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     vec = SMC(model_init, model_step, num_particles=1024)
     sh = SMC(model_init, model_step, num_particles=1024, mesh=mesh)
     vec.run(jax.random.PRNGKey(1), YS)

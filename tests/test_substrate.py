@@ -22,6 +22,7 @@ from repro.distributed import (
     quantize_int8,
     dequantize_int8,
     compress_error_feedback,
+    make_mesh,
 )
 
 
@@ -78,7 +79,7 @@ def test_checkpoint_topology_independent_restore(tmp_path):
 
     tree = _tree()
     save(str(tmp_path), 3, tree)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     step, out = restore(str(tmp_path), template=tree, shardings=sh)
     assert jnp.allclose(out["a"], tree["a"])
@@ -182,7 +183,7 @@ def test_param_sharding_rules_resolve():
     from repro import configs
     from repro.models import init_params
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = configs.get_smoke_config("dbrx-132b")
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     sh = param_shardings(params, mesh)
@@ -193,16 +194,18 @@ def test_param_sharding_rules_resolve():
     }
     assert by_name["embed"].spec == P("model", "data")
     we_g = [v for k, v in by_name.items() if k.endswith("we_g")][0]
-    assert we_g.spec == P(None, "model", "data", None)  # stacked + EP + FSDP
+    # stacked + EP + FSDP (specs come canonical: trailing Nones stripped,
+    # which JAX 0.9's PartitionSpec equality no longer ignores)
+    assert we_g.spec == P(None, "model", "data")
     ln = [v for k, v in by_name.items() if k.endswith("ln1")][0]
-    assert ln.spec == P(None, None) or ln.spec == P(None)
+    assert ln.spec == P()
 
 
 def test_batch_sharding_small_batch_replicates():
     from jax.sharding import PartitionSpec as P
     from repro.distributed import batch_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = batch_shardings({"x": jax.ShapeDtypeStruct((1, 8), jnp.int32)}, mesh)
     assert sh["x"].spec in (P(), P("data", None))  # 1 % 1 == 0 -> either fine
 
@@ -212,6 +215,6 @@ def test_divisibility_guard_drops_axis():
     from repro.distributed.sharding import _divisible
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = _divisible(P("model"), (9,), mesh)
     assert spec == P("model")  # 9 % 1 == 0 on the degenerate mesh

@@ -12,6 +12,7 @@ import pytest
 from repro import distributions as dist
 from repro import optim
 from repro.core import primitives as P
+from repro.distributed import make_mesh
 from repro.infer import (
     SVI,
     AutoNormal,
@@ -34,7 +35,7 @@ def normal_model(data):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
@@ -189,7 +190,7 @@ def test_python_scalar_param_init():
 def test_mesh_without_data_axis_works():
     """Generic mesh axis names fall back to the first axis instead of
     crashing on a missing 'data' axis."""
-    odd_mesh = jax.make_mesh((1,), ("x",))
+    odd_mesh = make_mesh((1,), ("x",))
     guide = AutoNormal(normal_model)
     svi = SVI(normal_model, guide, optim.Adam(0.05), Trace_ELBO(num_particles=4), mesh=odd_mesh)
     state = svi.init(jax.random.PRNGKey(0), DATA)
