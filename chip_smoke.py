@@ -46,7 +46,6 @@ import argparse
 import json
 import math
 import sys
-import time
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -632,48 +631,22 @@ def phase_mesh(sz: Sizes, seed: int = 0) -> dict:
 # ---------------------------------------------------------------------------
 
 
-class _CompileMeter:
-    """Backend compile seconds and persistent-cache hits, read from JAX's
-    monitoring events (a cache hit replaces a compile with a read), and the
-    executables that took over `SLOW_S` to compile or load."""
-
-    SLOW_S = 2.0
-
-    def __init__(self):
-        import jax
-
-        self.compile_s, self.cache_hits = 0.0, 0
-        self.slow: list = []  # [function name, seconds, cache hit]
-        self._hits_seen = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, fun_name="?", **kwargs):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-            # a hit is recorded inside the compile span it replaces
-            hit, self._hits_seen = self.cache_hits > self._hits_seen, self.cache_hits
-            if duration >= self.SLOW_S:
-                self.slow.append([fun_name, round(duration, 3), hit])
-
-    def _on_event(self, event, **kwargs):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return self.compile_s, self.cache_hits, len(self.slow)
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
-def run_phase(name, fn, sz: Sizes, meter: _CompileMeter) -> None:
+def run_phase(name, fn, sz: Sizes) -> None:
+    """Run one phase inside the telemetry span ``chip_smoke.<name>`` and print
+    its line: seconds, backend compile seconds and persistent-cache hits (a
+    hit replaces a compile with a read), from the span's JAX events."""
+    from repro import telemetry
     from repro.kernels import ops
 
-    c0, h0, s0 = meter.snapshot()
-    t0 = time.perf_counter()
-    check = fn(sz)
-    seconds = time.perf_counter() - t0
-    c1, h1, _ = meter.snapshot()
-    line = {"phase": name, "seconds": round(seconds, 3), "compile_s": round(c1 - c0, 3),
-            "cache_hits": h1 - h0, "slow_compiles": meter.slow[s0:],
+    with telemetry.span(f"chip_smoke.{name}") as record:
+        check = fn(sz)
+    events = record["jax_events"]
+    line = {"phase": name, "seconds": round(record["end"] - record["start"], 3),
+            "compile_s": round(events.get(BACKEND_COMPILE, 0.0), 3),
+            "cache_hits": events.get(telemetry.CACHE_HIT, 0),
             "backend": ops.resolve_backend(), "check": check}
     print(json.dumps(line), flush=True)
 
@@ -711,10 +684,9 @@ def main(argv=None) -> int:
         return 1
     print(json.dumps({"compile_cache": str(cache), "jax": jax.__version__}), flush=True)
 
-    meter = _CompileMeter()
     phases = (("mesh", phase_mesh),) if args.chips == 4 else PHASES
     for name, fn in phases:
-        run_phase(name, fn, FULL, meter)
+        run_phase(name, fn, FULL)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": len(devices)}}))
     return 0

@@ -74,7 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
-from .. import settings
+from .. import settings, telemetry
 from ..kernels import ops
 from .chees import ChEESState, chees_init, chees_update, halton_jitter
 from .util import init_to_uniform, initialize_model, potential_energy, transform_fn
@@ -245,6 +245,25 @@ class HMCState(NamedTuple):
     diverging: jax.Array  # this transition hit an energy error > threshold
 
 
+class FusedCounters(NamedTuple):
+    """The fused drivers' work, summed over every transition of a run
+    (warmup and draws). Device arrays; the host sums them when it reads."""
+
+    leapfrog_steps: jax.Array  # (C,) int32 leapfrog steps the chain took
+    grad_evals: jax.Array      # (C,) int32 value-and-gradient evaluations
+    #                            `ops.leapfrog` reports making on the chain's row
+    leapfrog_calls: jax.Array  # () int32 `ops.leapfrog` calls
+
+
+def _count_work(counters: FusedCounters, steps=0, reports=()) -> FusedCounters:
+    """Add leapfrog steps and the `ops.leapfrog_reports` of leapfrog calls."""
+    return FusedCounters(
+        counters.leapfrog_steps + steps,
+        counters.grad_evals + sum(reports),
+        counters.leapfrog_calls + len(reports),
+    )
+
+
 class FlatHMCState(NamedTuple):
     """State of the fused batched driver: ALL chains in one struct, positions
     raveled to a (C, D) matrix so the hot loop is dense batched linear
@@ -268,9 +287,13 @@ class FlatHMCState(NamedTuple):
     accept_prob: jax.Array  # (C,) last accept probabilities
     num_steps: jax.Array    # (C,) int32 leapfrog steps (diagnostics)
     diverging: jax.Array    # (C,) bool divergence flags
+    counters: FusedCounters
 
 
 class HMC:
+    # names the fused path's device scopes: ``repro.<scope_name>.<phase>``
+    scope_name = "hmc"
+
     def __init__(
         self,
         model: Optional[Callable] = None,
@@ -437,7 +460,16 @@ class HMC:
             jnp.zeros((C,)),
             jnp.zeros((C,), jnp.int32),
             jnp.zeros((C,), bool),
+            FusedCounters(
+                jnp.zeros((C,), jnp.int32),
+                jnp.zeros((C,), jnp.int32),
+                jnp.zeros((), jnp.int32),
+            ),
         )
+
+    def scope(self, phase: str):
+        """`jax.named_scope` of a phase of the fused path, for device traces."""
+        return jax.named_scope(f"repro.{self.scope_name}.{phase}")
 
     def _fused_adapt(self, state: FlatHMCState, accept_prob, z_batch, warmup_len):
         """Cross-chain analogue of `_adapt`: dual averaging on the MEAN
@@ -486,10 +518,11 @@ class HMC:
         )
         eps_c = jnp.broadcast_to(state.step_size, (C,)).astype(jnp.float32)
         n_c = jnp.broadcast_to(n, (C,)).astype(jnp.int32)
-        z_new, r_new, pe_new = ops.leapfrog(
-            state.z, r, inv_b, eps_c, n_c, pe_flat,
-            max_steps=self.max_num_steps, backend=backend, mesh=mesh,
-        )
+        with ops.leapfrog_reports() as reports:
+            z_new, r_new, pe_new = ops.leapfrog(
+                state.z, r, inv_b, eps_c, n_c, pe_flat,
+                max_steps=self.max_num_steps, backend=backend, mesh=mesh,
+            )
         energy1 = pe_new + 0.5 * jnp.sum(inv_b * r_new * r_new, axis=-1)
         delta = energy0 - energy1
         delta = jnp.where(jnp.isnan(delta), -jnp.inf, delta)
@@ -498,9 +531,10 @@ class HMC:
         accept = jax.random.uniform(key_accept, (C,)) < accept_prob
         z = jnp.where(accept[:, None], z_new, state.z)
         potential = jnp.where(accept, pe_new, state.potential)
-        da, step_size, (wf_mean, wf_m2, wf_n) = self._fused_adapt(
-            state, accept_prob, z, warmup_len
-        )
+        with self.scope("adapt"):
+            da, step_size, (wf_mean, wf_m2, wf_n) = self._fused_adapt(
+                state, accept_prob, z, warmup_len
+            )
         chees = state.chees
         if self.adapt_trajectory_length:
             chees_new = chees_update(
@@ -510,7 +544,7 @@ class HMC:
         return FlatHMCState(
             z, potential, state.rng_key, step_size, state.inv_mass, da,
             wf_mean, wf_m2, wf_n, chees, state.i + 1, accept_prob, n_c,
-            diverging,
+            diverging, _count_work(state.counters, n_c, reports),
         )
 
     def fused_finalize_warmup(self, state: FlatHMCState) -> FlatHMCState:
@@ -551,6 +585,8 @@ class NUTS(HMC):
     leapfrog steps in a random direction, multinomially sampling a proposal
     within the new subtree (progressive sampling), and stop on a U-turn
     between trajectory endpoints or on divergence."""
+
+    scope_name = "nuts"
 
     def sample_step(self, state: HMCState, pe_fn, warmup_len: int = 0) -> HMCState:
         key, key_mom, key_dirs, key_accept = jax.random.split(state.rng_key, 4)
@@ -721,6 +757,7 @@ class NUTS(HMC):
         diverging = jnp.zeros((C,), bool)
         sum_acc = jnp.zeros((C,))
         n_leap = jnp.zeros((C,), jnp.int32)
+        work = state.counters
 
         for j in range(self.max_tree_depth):
             key_j = jax.random.fold_in(key_loop, j)
@@ -733,12 +770,13 @@ class NUTS(HMC):
 
             def body(carry, t, dirs=dirs, stop=stop, key_in=key_in):
                 (z_e, r_e, z_p, pe_p, lw, s_turn, s_div, s_acc,
-                 z_f, r_f, started, taken) = carry
+                 z_f, r_f, started, taken, work) = carry
                 active = ~stop & ~s_turn & ~s_div
-                z_n, r_n, pe_n = ops.leapfrog(
-                    z_e, r_e, inv_b, eps * dirs, active.astype(jnp.int32),
-                    pe_flat, max_steps=1, backend=backend, mesh=mesh,
-                )
+                with ops.leapfrog_reports() as reports:
+                    z_n, r_n, pe_n = ops.leapfrog(
+                        z_e, r_e, inv_b, eps * dirs, active.astype(jnp.int32),
+                        pe_flat, max_steps=1, backend=backend, mesh=mesh,
+                    )
                 e_n = pe_n + 0.5 * jnp.sum(inv_b * r_n * r_n, axis=-1)
                 delta = e_n - energy0
                 delta = jnp.where(jnp.isnan(delta), jnp.inf, delta)
@@ -770,16 +808,19 @@ class NUTS(HMC):
                 r_e = jnp.where(upd[:, None], r_n, r_e)
                 started = started | upd
                 taken = taken + upd.astype(jnp.int32)
+                work = _count_work(work, reports=reports)
                 return (z_e, r_e, z_p, pe_p, lw, s_turn, s_div, s_acc,
-                        z_f, r_f, started, taken), None
+                        z_f, r_f, started, taken, work), None
 
             init = (
                 z_end, r_end, z_prop, pe_prop, jnp.full((C,), -jnp.inf),
                 jnp.zeros((C,), bool), jnp.zeros((C,), bool), jnp.zeros((C,)),
                 z_end, r_end, jnp.zeros((C,), bool), jnp.zeros((C,), jnp.int32),
+                work,
             )
-            (z_end, r_end, z_ps, pe_ps, lw_sub, turn_sub, div_sub, acc_sub,
-             _, _, _, taken), _ = jax.lax.scan(body, init, jnp.arange(2 ** j))
+            with self.scope("tree"):
+                (z_end, r_end, z_ps, pe_ps, lw_sub, turn_sub, div_sub, acc_sub,
+                 _, _, _, taken, work), _ = jax.lax.scan(body, init, jnp.arange(2 ** j))
 
             # biased progressive sampling between the old tree and the subtree
             total = jnp.logaddexp(log_w, lw_sub)
@@ -808,13 +849,14 @@ class NUTS(HMC):
             n_leap = n_leap + taken
 
         accept_prob = sum_acc / jnp.maximum(n_leap, 1)
-        da, step_size, (wf_mean, wf_m2, wf_n) = self._fused_adapt(
-            state, accept_prob, z_prop, warmup_len
-        )
+        with self.scope("adapt"):
+            da, step_size, (wf_mean, wf_m2, wf_n) = self._fused_adapt(
+                state, accept_prob, z_prop, warmup_len
+            )
         return FlatHMCState(
             z_prop, pe_prop, state.rng_key, step_size, state.inv_mass, da,
             wf_mean, wf_m2, wf_n, state.chees, state.i + 1, accept_prob,
-            n_leap, diverging,
+            n_leap, diverging, _count_work(work, steps=n_leap),
         )
 
 
@@ -1054,7 +1096,11 @@ class MCMC:
                 "num_steps": s.num_steps, "diverging": s.diverging,
             }
             batch = shard_chains(batch, mesh)
-            return s._replace(**batch)
+            c = s.counters
+            steps, evals = shard_chains((c.leapfrog_steps, c.grad_evals), mesh)
+            return s._replace(
+                **batch, counters=c._replace(leapfrog_steps=steps, grad_evals=evals)
+            )
 
         def driver(chain_keys, proto, dyn_leaves):
             self.num_traces += 1  # trace-time side effect (retrace detector)
@@ -1082,21 +1128,23 @@ class MCMC:
                 s = step(s)
                 if adapt_mm:
                     do = ((i + 1) % win == 0) & (i + 1 < W)
-                    s = jax.lax.cond(
-                        do,
-                        lambda s: s._replace(
-                            inv_mass=pooled_variance(s.wf_m2, s.wf_n),
-                            wf_mean=jnp.zeros_like(s.wf_mean),
-                            wf_m2=jnp.zeros_like(s.wf_m2),
-                            wf_n=jnp.zeros_like(s.wf_n),
-                        ),
-                        lambda s: s,
-                        s,
-                    )
+                    with kernel.scope("adapt"):
+                        s = jax.lax.cond(
+                            do,
+                            lambda s: s._replace(
+                                inv_mass=pooled_variance(s.wf_m2, s.wf_n),
+                                wf_mean=jnp.zeros_like(s.wf_mean),
+                                wf_m2=jnp.zeros_like(s.wf_m2),
+                                wf_n=jnp.zeros_like(s.wf_n),
+                            ),
+                            lambda s: s,
+                            s,
+                        )
                 return s, None
 
             if W > 0:
-                state, _ = jax.lax.scan(warmup_body, state, jnp.arange(W))
+                with kernel.scope("warmup"):
+                    state, _ = jax.lax.scan(warmup_body, state, jnp.arange(W))
             state = kernel.fused_finalize_warmup(state)
 
             def collect_body(s, _):
@@ -1121,7 +1169,8 @@ class MCMC:
                 }
                 return s, (s.z, extras)
 
-            state, (zs, extras) = jax.lax.scan(collect_body, state, None, length=S)
+            with kernel.scope("sample"):
+                state, (zs, extras) = jax.lax.scan(collect_body, state, None, length=S)
             zs = jnp.swapaxes(zs, 0, 1)  # (S, C, D) -> (C, S, D)
             extras = jax.tree_util.tree_map(
                 lambda x: jnp.swapaxes(x, 0, 1), extras
@@ -1141,54 +1190,63 @@ class MCMC:
         `init_params`, when given, is an *unbatched* pytree of unconstrained
         initial values broadcast to every chain (chains still decorrelate
         through their momenta/keys). Required for `potential_fn` kernels.
+
+        Runs inside the telemetry span ``mcmc.run`` (children
+        ``mcmc.model_setup`` and ``mcmc.call``); on the fused path the span's
+        counters are the run's `FusedCounters`, left on the device.
         """
-        key_setup, key_init = jax.random.split(rng_key)
-        kernel = self.kernel
-        if kernel.model is not None:
-            _, proto = kernel.setup(key_setup, *args, **kwargs)
-            randomize = init_params is None
-            if init_params is not None:
-                proto = init_params
-        else:
-            if init_params is None:
-                raise ValueError("potential_fn kernels require init_params=")
-            proto, randomize = init_params, False
-
-        C = self.num_chains
-        proto = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(jnp.asarray(x, jnp.float32), (C,) + jnp.shape(x)),
-            proto,
-        )
-        chain_keys = jax.random.split(key_init, C)
-
-        # static/dynamic partition of model args: arrays are traced (a fresh
-        # dataset of the same shape reuses the executable), everything else
-        # (plate sizes, flags) stays static so model control flow is unchanged
-        leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
-        is_dyn = tuple(isinstance(l, (jax.Array, np.ndarray)) for l in leaves)
-        dyn_leaves = [l for l, d in zip(leaves, is_dyn) if d]
-        static_leaves = tuple(None if d else l for l, d in zip(leaves, is_dyn))
-        # the kernel backend is a trace-time constant of the fused driver, so
-        # it joins the cache key (flipping REPRO_KERNEL_BACKEND between runs
-        # recompiles instead of silently reusing the old backend)
-        backend = ops.resolve_backend(None) if self.fused else None
-        exec_key = (randomize, treedef, is_dyn, static_leaves, self.fused, backend)
-        if self._exec is None or self._exec_key != exec_key:
-            if self.fused:
-                driver = self._build_fused_driver(
-                    randomize, treedef, is_dyn, static_leaves, backend
-                )
+        with telemetry.span("mcmc.run") as record:
+            key_setup, key_init = jax.random.split(rng_key)
+            kernel = self.kernel
+            if kernel.model is not None:
+                with telemetry.span("mcmc.model_setup"):
+                    _, proto = kernel.setup(key_setup, *args, **kwargs)
+                randomize = init_params is None
+                if init_params is not None:
+                    proto = init_params
             else:
-                driver = self._build_driver(randomize, treedef, is_dyn, static_leaves)
-            self._exec = jax.jit(driver)
-            self._exec_key = exec_key
-        states, z, extras = self._exec(chain_keys, proto, dyn_leaves)
-        self._last_state = states
-        self._extra_fields = extras
-        if kernel._transforms:
-            z = transform_fn(kernel._transforms, z)
-        self._samples = z
-        return self.get_samples()
+                if init_params is None:
+                    raise ValueError("potential_fn kernels require init_params=")
+                proto, randomize = init_params, False
+
+            C = self.num_chains
+            proto = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(jnp.asarray(x, jnp.float32), (C,) + jnp.shape(x)),
+                proto,
+            )
+            chain_keys = jax.random.split(key_init, C)
+
+            # static/dynamic partition of model args: arrays are traced (a fresh
+            # dataset of the same shape reuses the executable), everything else
+            # (plate sizes, flags) stays static so model control flow is unchanged
+            leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
+            is_dyn = tuple(isinstance(l, (jax.Array, np.ndarray)) for l in leaves)
+            dyn_leaves = [l for l, d in zip(leaves, is_dyn) if d]
+            static_leaves = tuple(None if d else l for l, d in zip(leaves, is_dyn))
+            # the kernel backend is a trace-time constant of the fused driver, so
+            # it joins the cache key (flipping REPRO_KERNEL_BACKEND between runs
+            # recompiles instead of silently reusing the old backend)
+            backend = ops.resolve_backend(None) if self.fused else None
+            exec_key = (randomize, treedef, is_dyn, static_leaves, self.fused, backend)
+            if self._exec is None or self._exec_key != exec_key:
+                if self.fused:
+                    driver = self._build_fused_driver(
+                        randomize, treedef, is_dyn, static_leaves, backend
+                    )
+                else:
+                    driver = self._build_driver(randomize, treedef, is_dyn, static_leaves)
+                self._exec = jax.jit(driver)
+                self._exec_key = exec_key
+            with telemetry.span("mcmc.call"):
+                states, z, extras = self._exec(chain_keys, proto, dyn_leaves)
+            if self.fused:
+                record["counters"].update(states.counters._asdict())
+            self._last_state = states
+            self._extra_fields = extras
+            if kernel._transforms:
+                z = transform_fn(kernel._transforms, z)
+            self._samples = z
+            return self.get_samples()
 
     def get_samples(self, group_by_chain: bool = False):
         """Posterior samples in constrained space: ``(chain, draw, ...)`` when

@@ -60,7 +60,8 @@ def shared_grad_leapfrog(z, r, inv_mass, eps, num_steps, max_steps, vg_fn):
     z, r, inv_mass: (c, D); eps, num_steps: (c, 1); vg_fn: (c, D) ->
     ((c,) potential, (c, D) gradient). Runs `min(max(num_steps), max_steps)`
     iterations of the one-gradient-per-step form with per-chain active
-    masks; returns (z', r', potential(z')).
+    masks; returns (z', r', potential(z'), evaluations), the last the
+    number of `vg_fn` calls made, each on every row.
     """
     live = num_steps > 0  # (c, 1)
     nmax = jnp.minimum(jnp.max(num_steps), max_steps)
@@ -83,11 +84,11 @@ def shared_grad_leapfrog(z, r, inv_mass, eps, num_steps, max_steps, vg_fn):
         return (i + 1, z, r, g)
 
     init = (jnp.zeros((), jnp.int32), z, r, g0)
-    _, z, r, g = jax.lax.while_loop(cond, body, init)
+    steps, z, r, g = jax.lax.while_loop(cond, body, init)
     # repay half of the final full kick -> trailing half-kick
     r = jnp.where(live, r + 0.5 * eps * g, r)
     pe, _ = vg_fn(z)
-    return z, r, pe
+    return z, r, pe, steps + 2
 
 
 def _leapfrog_kernel(
@@ -95,7 +96,7 @@ def _leapfrog_kernel(
 ):
     nconsts = len(const_shapes)
     const_refs = rest[:nconsts]
-    zo_ref, ro_ref, pe_ref = rest[nconsts:]
+    zo_ref, ro_ref, pe_ref, ev_ref = rest[nconsts:]
     consts = [
         c[...].reshape(shape) for c, shape in zip(const_refs, const_shapes)
     ]
@@ -107,13 +108,14 @@ def _leapfrog_kernel(
 
         return jax.vmap(one)(z_block)
 
-    z, r, pe = shared_grad_leapfrog(
+    z, r, pe, evals = shared_grad_leapfrog(
         z_ref[...], r_ref[...], minv_ref[...], eps_ref[...], n_ref[...],
         max_steps, vg_fn,
     )
     zo_ref[...] = z
     ro_ref[...] = r
     pe_ref[...] = pe[:, None]
+    ev_ref[...] = jnp.full(ev_ref.shape, evals, jnp.int32)
 
 
 def leapfrog_fused(
@@ -129,7 +131,9 @@ def leapfrog_fused(
     block_chains: int = 8,
     interpret: bool = False,
 ):
-    """Fused leapfrog over a (C, D) block of chains; returns (z', r', pe').
+    """Fused leapfrog over a (C, D) block of chains; returns (z', r', pe',
+    evals), evals (C,) int32: the value-and-gradient evaluations the kernel
+    made on each chain's row (every row of a block pays for the block).
 
     `kernels/ops.leapfrog` is the public entry point — it resolves the
     backend, traces the potential, and pads C to the block size. Chains are
@@ -176,14 +180,20 @@ def leapfrog_fused(
             pl.BlockSpec((bc, D), lambda i: (i, 0)),
             pl.BlockSpec((bc, D), lambda i: (i, 0)),
             pl.BlockSpec((bc, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bc, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Cp, D), jnp.float32),
             jax.ShapeDtypeStruct((Cp, D), jnp.float32),
             jax.ShapeDtypeStruct((Cp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Cp, 1), jnp.int32),
         ],
         compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
+        # the program's name for the kernel: a device trace shows it in the
+        # instruction's text. A `name=` or a named scope around the call would
+        # rename the instruction itself, which trace readers match by.
+        metadata={"name": "repro.leapfrog"},
     )(
         z,
         r,
@@ -192,5 +202,5 @@ def leapfrog_fused(
         num_steps[:, None].astype(jnp.int32),
         *const_in,
     )
-    z_new, r_new, pe = out
-    return z_new[:C], r_new[:C], pe[:C, 0]
+    z_new, r_new, pe, evals = out
+    return z_new[:C], r_new[:C], pe[:C, 0], evals[:C, 0]

@@ -17,7 +17,9 @@ separate executable instead of clobbering one cache entry.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 import warnings
 from typing import Optional
 
@@ -378,6 +380,31 @@ def resample(
 # -- fused HMC leapfrog (MCMC hot path) ---------------------------------------
 
 
+_leapfrog_sinks = threading.local()
+
+
+@contextlib.contextmanager
+def leapfrog_reports():
+    """Collect what each `leapfrog` call traced inside the block reports:
+    one (C,) int32 array per call, the value-and-gradient evaluations that
+    call made on each chain's row, frozen rows included. Each backend counts
+    its own loop: the kernels ``2 + steps`` for a block of chains (the first
+    gradient, one per step of the block's longest trajectory, the final
+    potential), the reference ``2 steps + 1`` for the whole batch.
+
+    The arrays are values of the trace the block runs in, so the caller adds
+    them up there (the MCMC drivers carry the sums through their loops)."""
+    sinks = getattr(_leapfrog_sinks, "stack", None)
+    if sinks is None:
+        sinks = _leapfrog_sinks.stack = []
+    reports: list = []
+    sinks.append(reports)
+    try:
+        yield reports
+    finally:
+        sinks.pop()
+
+
 def leapfrog(
     z,
     r,
@@ -414,28 +441,38 @@ def leapfrog(
 
     ``mesh``: the mesh the chain axis is sharded on; the kernel then runs per
     device on its chains (see `_on_mesh`).
+
+    Inside `leapfrog_reports` the call also reports the value-and-gradient
+    evaluations it made on each chain's row.
     """
     backend = resolve_backend(backend)
     if backend == "reference":
-        return ref.leapfrog_ref(
+        z, r, pe, evals = ref.leapfrog_ref(
             z, r, inv_mass, step_size, num_steps, potential_fn,
             max_steps=max_steps,
         )
-    # traced on an unsharded (D,) shape: the jaxpr is replayed per device
-    closed = jax.make_jaxpr(jax.value_and_grad(potential_fn))(
-        jax.ShapeDtypeStruct(z.shape[1:], z.dtype)
-    )
-    consts = [jnp.asarray(c) for c in closed.consts]
-
-    def fused(z, r, inv_mass, step_size, num_steps, *consts):
-        return leapfrog_fused(
-            z, r, inv_mass, step_size, num_steps, consts,
-            jaxpr=closed.jaxpr, max_steps=max_steps, block_chains=block_chains,
-            interpret=(backend == "interpret"),
+    else:
+        # traced on an unsharded (D,) shape: the jaxpr is replayed per device
+        closed = jax.make_jaxpr(jax.value_and_grad(potential_fn))(
+            jax.ShapeDtypeStruct(z.shape[1:], z.dtype)
         )
+        consts = [jnp.asarray(c) for c in closed.consts]
 
-    args = (z, r, inv_mass, step_size, num_steps, *consts)
-    return _on_mesh(fused, args, (True,) * 5 + (False,) * len(consts), mesh)
+        def fused(z, r, inv_mass, step_size, num_steps, *consts):
+            return leapfrog_fused(
+                z, r, inv_mass, step_size, num_steps, consts,
+                jaxpr=closed.jaxpr, max_steps=max_steps, block_chains=block_chains,
+                interpret=(backend == "interpret"),
+            )
+
+        args = (z, r, inv_mass, step_size, num_steps, *consts)
+        z, r, pe, evals = _on_mesh(
+            fused, args, (True,) * 5 + (False,) * len(consts), mesh
+        )
+    sinks = getattr(_leapfrog_sinks, "stack", None)
+    if sinks:
+        sinks[-1].append(evals)
+    return z, r, pe
 
 
 # -- information-form Gaussian combine / Kalman scan (Gaussian semiring) ------

@@ -74,7 +74,8 @@ def leapfrog_ref(z, r, inv_mass, step_size, num_steps, potential_fn, *, max_step
     z, r, inv_mass: (C, D); step_size: (C,) (sign = integration direction);
     num_steps: (C,) int (0 = chain frozen, position/momentum pass through).
     Runs `min(max(num_steps), max_steps)` masked iterations; returns
-    (z', r', potential(z')).
+    (z', r', potential(z'), evals), evals (C,) int32: the value-and-gradient
+    evaluations made on each row (every row, at every iteration).
     """
     vg = jax.vmap(jax.value_and_grad(potential_fn))
     eps = step_size[:, None].astype(jnp.float32)
@@ -96,9 +97,9 @@ def leapfrog_ref(z, r, inv_mass, step_size, num_steps, potential_fn, *, max_step
         r = jnp.where(active, r2, r)
         return (i + 1, z, r)
 
-    _, z, r = jax.lax.while_loop(cond, body, (jnp.zeros((), jnp.int32), z, r))
+    steps, z, r = jax.lax.while_loop(cond, body, (jnp.zeros((), jnp.int32), z, r))
     pe, _ = vg(z)
-    return z, r, pe
+    return z, r, pe, jnp.full(pe.shape, 2 * steps + 1, jnp.int32)
 
 
 _LOG_2PI = 1.8378770664093453

@@ -40,24 +40,32 @@ def test_phase_at_tiny_size(name, kernels_interpreted):
 def test_phase_line_reports_compiles(capsys):
     import jax
 
-    meter = chip_smoke._CompileMeter()
     chip_smoke.run_phase("add", lambda sz: {"v": float(jax.jit(lambda a: a + 1)(1.0))},
-                         chip_smoke.Sizes(**TINY), meter)
+                         chip_smoke.Sizes(**TINY))
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["phase"] == "add" and line["check"] == {"v": 2.0}
     assert line["compile_s"] >= 0 and line["cache_hits"] >= 0
-    assert isinstance(line["slow_compiles"], list)
+    from repro import telemetry
+
+    assert telemetry.spans("chip_smoke.add")[-1]["jax_events"], "no compile event seen"
 
 
-def test_compile_meter_attributes_cache_hits():
-    meter = chip_smoke._CompileMeter()
-    compiled = "/jax/core/compile/backend_compile_duration"
-    meter._on_event("/jax/compilation_cache/cache_hits")
-    meter._on_duration(compiled, 3.0, fun_name="hit")
-    meter._on_duration(compiled, 5.0, fun_name="miss")
-    meter._on_duration(compiled, 0.1, fun_name="fast")
-    assert meter.slow[-2:] == [["hit", 3.0, True], ["miss", 5.0, False]]
-    assert meter.snapshot()[1] >= 1
+def test_compile_meter_attributes_cache_hits(capsys):
+    """A phase's line counts the compile seconds and cache hits that JAX
+    reports while the phase runs, and nothing from before it."""
+    from repro import telemetry
+
+    telemetry._on_event(telemetry.CACHE_HIT)  # outside any phase: no one's
+
+    def phase(sz):
+        telemetry._on_event(telemetry.CACHE_HIT)
+        telemetry._on_duration(chip_smoke.BACKEND_COMPILE, 3.0, fun_name="hit")
+        return {}
+
+    chip_smoke.run_phase("hits", phase, chip_smoke.Sizes(**TINY))
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["cache_hits"] == 1
+    assert line["compile_s"] == pytest.approx(3.0)
 
 
 def test_mesh_phase_on_four_virtual_devices():

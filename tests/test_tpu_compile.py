@@ -177,3 +177,39 @@ def test_leapfrog_schools(one_chip, max_steps):
         one_chip,
         ((C, D), F32), ((C, D), F32), ((C, D), F32), ((C,), F32), ((C,), I32),
     )
+
+
+def test_fused_nuts_driver_names_its_kernel(one_chip):
+    """The fused NUTS driver at a small size: the device trace names an op
+    after its instruction, which is the innermost scope around it, so the
+    program's scopes sit outside the loops' bodies and the kernel stays the
+    instruction `closed_call` (what `leapfrog_roofline` matches); its
+    metadata carries the program's names."""
+    import re
+
+    from repro.infer import MCMC, NUTS
+
+    def model(y):
+        mu = P.sample("mu", dist.Normal(0.0, 5.0))
+        with P.plate("N", y.shape[0]):
+            P.sample("obs", dist.Normal(mu, 1.0), obs=y)
+
+    C, y = 16, jnp.zeros(8)
+    mcmc = MCMC(NUTS(model, max_tree_depth=2), num_warmup=1, num_samples=1, num_chains=C)
+    _, proto = mcmc.kernel.setup(jax.random.PRNGKey(0), y)
+    _, treedef = jax.tree_util.tree_flatten(((y,), {}))
+    driver = mcmc._build_fused_driver(True, treedef, (True,), (None,), "tpu")
+    args = (
+        jax.ShapeDtypeStruct((C, 2), jnp.uint32, sharding=one_chip),
+        {k: jax.ShapeDtypeStruct((C,) + jnp.shape(v), F32, sharding=one_chip)
+         for k, v in proto.items()},
+        [jax.ShapeDtypeStruct(y.shape, F32, sharding=one_chip)],
+    )
+    text = jax.jit(driver).lower(*args).compile().as_text()
+    kernels = re.findall(r"^\s*(%\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                         text, re.M)
+    assert kernels and all(re.match(r"%closed_call[.\d]*$", k) for k in kernels), kernels
+    assert '"name":"repro.leapfrog"' in text
+    for scope in ("repro.nuts.warmup/", "repro.nuts.sample/", "repro.nuts.tree/",
+                  "repro.nuts.adapt/"):
+        assert scope in text, scope
