@@ -580,6 +580,35 @@ class _TreeState(NamedTuple):
     n_leapfrog: jax.Array
 
 
+def _subtree_leaf(z_ck, r_ck, z, r, t, direction, inv_mass):
+    """Take leaf `t` (0-based, in the order built) of a subtree into the
+    iterative U-turn check over its balanced sub-trees (the checkpoint
+    scheme of NumPyro's `_is_iterative_turning` and Stan's sub-tree checks).
+
+    An even leaf is stored at checkpoint `popcount(t >> 1)`; an odd leaf
+    closes the balanced sub-trees that end at it, and is checked against
+    the first leaf of each, held at checkpoints `idx_min..idx_max`. Only
+    balanced sub-trees are checked, so the verdict does not depend on the
+    direction the subtree was built in, as NUTS's reversibility needs.
+
+    z_ck, r_ck: (K, C, D) checkpoints, K at least the subtree's depth (and
+    1); z, r: (C, D) the new leaf; t: scalar int32, the same for every
+    chain; direction: (C,) +1 or -1; inv_mass: (C, D).
+    Returns the checkpoints and a (C,) bool: a sub-tree ending at the leaf
+    makes a U-turn."""
+    idx_max = jax.lax.population_count(t >> 1)
+    idx_min = idx_max - jax.lax.population_count((t ^ (t + 1)) >> 1) + 1
+    slot = jnp.where(t % 2 == 0, idx_max, z_ck.shape[0])  # odd leaves: dropped
+    z_ck = z_ck.at[slot].set(z, mode="drop")
+    r_ck = r_ck.at[slot].set(r, mode="drop")
+    # direction-normalized: dz points forward along the trajectory
+    dz = direction[:, None] * (z - z_ck)
+    turn = ((jnp.sum(dz * inv_mass * r_ck, axis=-1) < 0)
+            | (jnp.sum(dz * inv_mass * r, axis=-1) < 0))
+    k = jnp.arange(z_ck.shape[0])[:, None]
+    return z_ck, r_ck, jnp.any(turn & (k >= idx_min) & (k <= idx_max), axis=0)
+
+
 class NUTS(HMC):
     """No-U-Turn sampler. At each doubling j we extend the trajectory by 2^j
     leapfrog steps in a random direction, multinomially sampling a proposal
@@ -733,9 +762,14 @@ class NUTS(HMC):
         masks, so every leapfrog step in the trajectory is a single
         `ops.leapfrog` call over all chains (`num_steps` 1 where the chain is
         still growing its tree, 0 where it has stopped) — the chain batch
-        never leaves the mesh, and the doubling-j subtree is a scan of
-        exactly 2^j steps instead of the per-chain path's fixed
-        2^max_tree_depth bound."""
+        never leaves the mesh. The doubling-j subtree is a while loop of at
+        most 2^j steps that ends as soon as none of its chains is still
+        growing, so a transition makes as many calls as the longest tree in
+        the batch has steps (`FusedCounters.leapfrog_calls`), and a level no
+        chain enters makes none. Random numbers are keyed by level and step,
+        so a chain's draws do not depend on how long the others' trees run.
+        Inside a subtree a U-turn is checked over its balanced sub-trees
+        (`_subtree_leaf`), as reversibility needs."""
         C, D = state.z.shape
         key = jax.random.fold_in(state.rng_key, state.i)
         key_mom, key_loop = jax.random.split(key)
@@ -768,11 +802,18 @@ class NUTS(HMC):
             z_end = jnp.where(fwd, z_right, z_left)
             r_end = jnp.where(fwd, r_right, r_left)
 
-            def body(carry, t, dirs=dirs, stop=stop, key_in=key_in):
-                (z_e, r_e, z_p, pe_p, lw, s_turn, s_div, s_acc,
-                 z_f, r_f, started, taken, work) = carry
+            def more(carry, n=2 ** j, stop=stop):
+                t, s_turn, s_div = carry[0], carry[6], carry[7]
+                return (t < n) & jnp.any(~stop & ~s_turn & ~s_div)
+
+            def body(carry, dirs=dirs, stop=stop, key_in=key_in):
+                (t, z_e, r_e, z_p, pe_p, lw, s_turn, s_div, s_acc,
+                 z_ck, r_ck, taken, work) = carry
                 active = ~stop & ~s_turn & ~s_div
-                with ops.leapfrog_reports() as reports:
+                # a device trace names an op after the innermost scope over
+                # it: the kernel keeps the name it had in a scan's body
+                # (`closed_call`), not the while loop's `body`
+                with ops.leapfrog_reports() as reports, jax.named_scope("closed_call"):
                     z_n, r_n, pe_n = ops.leapfrog(
                         z_e, r_e, inv_b, eps * dirs, active.astype(jnp.int32),
                         pe_flat, max_steps=1, backend=backend, mesh=mesh,
@@ -792,35 +833,28 @@ class NUTS(HMC):
                 z_p = jnp.where(sel[:, None], z_n, z_p)
                 pe_p = jnp.where(sel, pe_n, pe_p)
                 s_acc = s_acc + jnp.where(upd, jnp.minimum(1.0, jnp.exp(-delta)), 0.0)
-                first = upd & ~started
-                z_f = jnp.where(first[:, None], z_n, z_f)
-                r_f = jnp.where(first[:, None], r_n, r_f)
-                # direction-normalized U-turn within the growing subtree
-                dz = dirs[:, None] * (z_n - z_f)
-                turn_n = (
-                    (row_dot(dz, inv_b * r_f) < 0)
-                    | (row_dot(dz, inv_b * r_n) < 0)
-                ) & started  # need >= 2 points in the subtree
+                z_ck, r_ck, turn_n = _subtree_leaf(z_ck, r_ck, z_n, r_n, t, dirs, inv_b)
                 s_turn = s_turn | (upd & turn_n)
                 s_div = s_div | (upd & div_n)
                 lw = jnp.where(upd, lw2, lw)
                 z_e = jnp.where(upd[:, None], z_n, z_e)
                 r_e = jnp.where(upd[:, None], r_n, r_e)
-                started = started | upd
                 taken = taken + upd.astype(jnp.int32)
                 work = _count_work(work, reports=reports)
-                return (z_e, r_e, z_p, pe_p, lw, s_turn, s_div, s_acc,
-                        z_f, r_f, started, taken, work), None
+                return (t + 1, z_e, r_e, z_p, pe_p, lw, s_turn, s_div, s_acc,
+                        z_ck, r_ck, taken, work)
 
+            # the balanced sub-trees' first leaves (`_subtree_leaf`)
+            ckpts = jnp.zeros((max(j, 1), C, D), state.z.dtype)
             init = (
+                jnp.zeros((), jnp.int32),
                 z_end, r_end, z_prop, pe_prop, jnp.full((C,), -jnp.inf),
                 jnp.zeros((C,), bool), jnp.zeros((C,), bool), jnp.zeros((C,)),
-                z_end, r_end, jnp.zeros((C,), bool), jnp.zeros((C,), jnp.int32),
-                work,
+                ckpts, ckpts, jnp.zeros((C,), jnp.int32), work,
             )
             with self.scope("tree"):
-                (z_end, r_end, z_ps, pe_ps, lw_sub, turn_sub, div_sub, acc_sub,
-                 _, _, _, taken, work), _ = jax.lax.scan(body, init, jnp.arange(2 ** j))
+                (_, z_end, r_end, z_ps, pe_ps, lw_sub, turn_sub, div_sub, acc_sub,
+                 _, _, taken, work) = jax.lax.while_loop(more, body, init)
 
             # biased progressive sampling between the old tree and the subtree
             total = jnp.logaddexp(log_w, lw_sub)
